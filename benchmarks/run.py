@@ -94,6 +94,8 @@ def main() -> None:
             print(f"{name:32s} {desc}")
         return
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import experiments as E
 
     runs = {

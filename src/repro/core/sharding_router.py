@@ -52,7 +52,7 @@ import concurrent.futures
 import pickle
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -281,7 +281,7 @@ class ShardRouter:
                  replicas: int = 1,
                  sync_every: int = 64,
                  transport: Optional[str] = None,
-                 device_claim: Optional[bool] = None,
+                 device_claim: Union[bool, str, None] = None,
                  lease_s: Optional[float] = None,
                  steal_recv_timeout: Optional[float] = 30.0):
         if num_shards < 1:
@@ -732,7 +732,7 @@ class ShardRouter:
                         replicas: int = 1,
                         sync_every: int = 64,
                         transport: Optional[str] = None,
-                        device_claim: Optional[bool] = None,
+                        device_claim: Union[bool, str, None] = None,
                         capacity: int = 1 << 16) -> "ShardRouter":
         """Rebuild a router from per-shard restored state, in shard order:
         ``shard_states`` is one ``(store, meta)`` pair per shard as cut by

@@ -27,7 +27,7 @@ nodes gives flexibility ... load balancing").
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -40,7 +40,7 @@ from repro.core.transactions import TxnLog
 class WorkQueue:
     def __init__(self, num_workers: int, store: Optional[ColumnStore] = None,
                  txn_log: Optional[TxnLog] = None, capacity: int = 1 << 16,
-                 device_claim: Optional[bool] = None,
+                 device_claim: Union[bool, str, None] = None,
                  lease_s: Optional[float] = None):
         self.store = store or ColumnStore(capacity=capacity)
         if lease_s is not None:
@@ -54,7 +54,12 @@ class WorkQueue:
         if device_claim is None:
             from repro.flags import wq_device_claim
             device_claim = wq_device_claim()
-        self.device_claim = bool(device_claim)
+        if device_claim not in (False, True, "interpret"):
+            raise ValueError(f"device_claim must be a bool or 'interpret', "
+                             f"got {device_claim!r}")
+        # True: the compiled kernel on the accelerator; "interpret": the same
+        # kernel in Pallas interpret mode (CPU tests)
+        self.device_claim = device_claim
         # ready cursor per partition: no READY row of partition w exists at a
         # row index < _cursor[w]. Claims advance it; any transition that can
         # re-create READY rows at lower indices lowers it again.
@@ -520,7 +525,8 @@ class WorkQueue:
         status = self.store.col("status")[start:]
         wid_full = self.store.col("worker_id")
         claim_mask, new_status = wq_claim_columns(
-            status, wid_full[start:], num_workers=self.num_workers, k=k)
+            status, wid_full[start:], num_workers=self.num_workers, k=k,
+            interpret=self.device_claim == "interpret")
         rows = np.nonzero(claim_mask)[0] + start
         # the kernel's rank trick degenerates to rank 0 for rows whose
         # partition id is outside [0, W) (all-zero one-hot), so it "claims"

@@ -11,13 +11,16 @@ claim_all's primary phase through the wq_claim Pallas op on the accelerator
 instead of the host numpy fast-path (the queue samples the flag once in
 __init__; flip ``wq.device_claim`` to switch an existing queue). Defaults
 from the REPRO_WQ_DEVICE_CLAIM env var (off unless set to 1/true/yes);
-``device_claims()`` scopes the construction-time default.
+``device_claims()`` scopes the construction-time default. The kernel is
+compiled for the accelerator; ``device_claims("interpret")`` runs it in
+Pallas interpret mode instead (CPU tests) — nothing switches implicitly.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
 import os
+from typing import Union
 
 _SCAN_UNROLL = contextvars.ContextVar("repro_scan_unroll", default=False)
 
@@ -27,12 +30,12 @@ _WQ_DEVICE_CLAIM = contextvars.ContextVar(
     in ("1", "true", "yes"))
 
 
-def wq_device_claim() -> bool:
+def wq_device_claim() -> Union[bool, str]:
     return _WQ_DEVICE_CLAIM.get()
 
 
 @contextlib.contextmanager
-def device_claims(on: bool = True):
+def device_claims(on: Union[bool, str] = True):
     """Construction-time default for WorkQueue(device_claim=None) within the
     scope; queues built earlier keep whatever they sampled."""
     tok = _WQ_DEVICE_CLAIM.set(on)

@@ -12,10 +12,17 @@ import jax
 from repro.configs.base import MULTI_POD, SINGLE_POD, MeshConfig
 
 
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    # Auto axes: the sharding rules place arrays with with_sharding_constraint,
+    # which refuses the Explicit axes jax.make_mesh makes by default
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -24,4 +31,4 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """1-device mesh for smoke runs through the same code path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

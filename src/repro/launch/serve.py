@@ -10,6 +10,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config, smoke_config
 from repro.runtime.executor import ServeExecutor
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     ex = ServeExecutor(cfg, slots=args.slots,
                        max_len=64 if args.smoke else 4096)

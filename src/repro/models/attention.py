@@ -1,8 +1,8 @@
 """GQA attention with KV cache, causal / sliding-window / cross variants.
 
-The compute core dispatches to the Pallas flash/decode kernels when
-``repro.kernels.ops.pallas_enabled()`` (TPU target, or interpret mode in
-tests); otherwise to the pure-jnp reference (identical math).
+The compute core dispatches to the compiled Pallas flash/decode kernels when
+``cfg.attn_impl == "pallas"``; otherwise to the chunked or pure-jnp reference
+path (identical math).
 """
 from __future__ import annotations
 
@@ -76,11 +76,12 @@ def _sdpa(q, k, v, *, cfg: ModelConfig, causal, window=0, q_offset=0,
           kv_len=None):
     impl = cfg.attn_impl
     if impl == "pallas":
-        from repro.kernels import ops as kops  # late import: optional dep
         if q.shape[1] == 1:                # decode: 1 query token
-            return kops.decode_attention(q, k, v, kv_len=kv_len, window=window)
+            from repro.kernels.decode_attention.ops import decode_attention
+            return decode_attention(q, k, v, kv_len=kv_len)
         if kv_len is None and isinstance(q_offset, int) and q_offset == 0:
-            return kops.flash_attention(q, k, v, causal=causal, window=window)
+            from repro.kernels.flash_attention.ops import flash_attention
+            return flash_attention(q, k, v, causal=causal, window=window)
         impl = "chunked"                   # kernel has no cache-tail variant
     if impl == "chunked" and q.shape[1] > 1 and kv_len is None \
             and isinstance(q_offset, int) and q_offset == 0:

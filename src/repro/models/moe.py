@@ -30,13 +30,7 @@ from repro.sharding import current_rules, shard
 
 Params = Dict[str, Any]
 
-# jax.shard_map(check_vma=) landed in jax 0.5; on older jaxlibs the API lives
-# in jax.experimental with the check_rep= spelling
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _shard_map = functools.partial(_shard_map_impl, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 EP_SHARDS = 16          # production "model" axis size; expert-dim padding unit
 CAPACITY_FACTOR = 1.25

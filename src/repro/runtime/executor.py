@@ -31,8 +31,8 @@ from repro.core.steering import SteeringEngine
 from repro.core.supervisor import SecondarySupervisor, Supervisor
 from repro.core.workqueue import WorkQueue
 from repro.data.pipeline import DataConfig, batch_for
-from repro.launch.steps import init_train_state, make_serve_step, \
-    make_train_step
+from repro.launch.steps import init_train_state, jit_train_step, \
+    make_serve_step
 from repro.models.registry import build_model
 
 
@@ -126,7 +126,7 @@ class TrainExecutor:
             max_workers=1, thread_name_prefix="steering")
         self._steer_future: Optional[concurrent.futures.Future] = None
         self.last_steering: Optional[Dict[str, object]] = None
-        self.step_fn = jax.jit(make_train_step(cfg))
+        self.step_fn = jit_train_step(cfg)
         self.state = init_train_state(cfg, jax.random.PRNGKey(seed))
         self.step = 0
         self.reaped_total = 0

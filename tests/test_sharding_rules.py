@@ -64,6 +64,7 @@ def test_spmd_training_on_8_cpu_devices():
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, dataclasses, json
         from repro.configs import smoke_config, SHAPES
@@ -72,7 +73,8 @@ def test_spmd_training_on_8_cpu_devices():
                                         train_state_shardings)
         from repro.models.registry import train_input_specs
         cfg = dataclasses.replace(smoke_config("granite-moe-3b-a800m"))
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = dataclasses.replace(SHAPES["train_4k"], seq_len=32,
                                     global_batch=8)
         rules = SR.make_rules(cfg, shape, mesh)

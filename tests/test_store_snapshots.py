@@ -134,9 +134,9 @@ def test_q8_and_prune_write_live_store_inside_sweep():
 def test_device_claim_flag_matches_reference():
     from repro.flags import device_claims, wq_device_claim
     assert not wq_device_claim()
-    with device_claims():
+    with device_claims("interpret"):
         wq_dev = WorkQueue(num_workers=3)        # picks the flag up
-        assert wq_dev.device_claim
+        assert wq_dev.device_claim == "interpret"
     wq_ref = WorkQueue(num_workers=3)
     assert not wq_ref.device_claim
     wq_dev.add_tasks(0, 20)
@@ -153,7 +153,7 @@ def test_device_claim_routes_orphaned_partitions_to_steal_pool():
     'claims' those at rank 0, so the device path must divert them to the
     steal pool exactly like the host path does."""
     results = {}
-    for device in (False, True):
+    for device in (False, "interpret"):
         wq = WorkQueue(num_workers=4, device_claim=device)
         wq.add_tasks(0, 12)
         out = wq.claim_all(k=1, now=0.0)          # 4 RUNNING, one per worker
@@ -174,7 +174,7 @@ def test_device_claim_routes_orphaned_partitions_to_steal_pool():
     for phase in (0, 1):                          # device path == host path
         for w in results[False][phase]:
             assert np.array_equal(results[False][phase][w],
-                                  results[True][phase][w])
+                                  results["interpret"][phase][w])
 
 
 def test_snapshot_id_index_and_q7_vectorized_walk():
