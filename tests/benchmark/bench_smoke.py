@@ -1,0 +1,32 @@
+"""Smoke-size copies of the benchmark's cells, for CPU tests of the harness."""
+import copy
+import json
+import os
+import shutil
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+CHIP = os.path.join(REPO, "benchmarks", "chip")
+if CHIP not in sys.path:
+    sys.path.insert(0, CHIP)
+
+SMOKE = {"num_layers": 2, "d_model": 64, "num_heads": 4, "num_kv_heads": 2,
+         "head_dim": 16, "d_ff": 128, "vocab_size": 256, "seq_len": 16,
+         "batch_size": 4}
+
+
+def smoke_base(tmp, tasks=400, dtype="float32", size=None):
+    """A copy of the benchmark's files with every configuration cut to a
+    CPU-sized payload (``SMOKE``, or ``size``) and backlog. Returns
+    (base dir, BENCHMARK dict)."""
+    base = os.path.join(str(tmp), "chip")
+    shutil.copytree(CHIP, base, ignore=shutil.ignore_patterns("__pycache__"))
+    for f in os.listdir(os.path.join(base, "configs")):
+        p = os.path.join(base, "configs", f)
+        c = json.load(open(p))
+        c["payload"].update(size or SMOKE, dtype=dtype)
+        c["tasks"] = tasks
+        json.dump(c, open(p, "w"))
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    return base, copy.deepcopy(bench)
