@@ -62,6 +62,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core import transport as transport_mod
 from repro.core import wire
 from repro.core.schema import Status
@@ -1066,9 +1067,9 @@ class ShippedDeltaReplicator(Replicator):
             # ONE queue item per sync: the shipper sees the whole staged
             # span in a single burst, so its unacked window pipelines
             # across every chunk instead of draining at chunk boundaries
-            self._shipq.put(wire.stage_delta(
+            self._shipq.put((tracing.current(), wire.stage_delta(
                 log.slice(lo, hi), lo,
-                chunk_records=self.chunk_records))  # full q -> block
+                chunk_records=self.chunk_records)))  # full q -> block
             self.enq_offset = hi
         if upto_version is not None:
             # version-exact callers need the replica AT the version when
@@ -1112,7 +1113,8 @@ class ShippedDeltaReplicator(Replicator):
         :class:`wire.DeltaEncoder`), ship with a bounded unacked window,
         harvest acks. Every dequeued item is task_done'd exactly once —
         on success, error, or after close — so ``flush()``/``close()``
-        can never hang on a lost item."""
+        can never hang on a lost item. Each item carries the id of the span
+        that staged it, the cause of the burst's spans."""
         q = self._shipq
         while True:
             item = q.get()
@@ -1132,7 +1134,8 @@ class ShippedDeltaReplicator(Replicator):
                 burst.append(nxt)
             try:
                 if not self._closed:
-                    self._ship_burst([c for item in burst for c in item])
+                    self._ship_burst([c for _, item in burst for c in item],
+                                     burst[0][0])
             except Exception as e:                        # noqa: BLE001
                 if self._ship_error is None:
                     self._ship_error = e   # flush()/next sync re-raises
@@ -1143,7 +1146,7 @@ class ShippedDeltaReplicator(Replicator):
                 q.task_done()
                 return
 
-    def _ship_burst(self, chunks: Sequence) -> None:
+    def _ship_burst(self, chunks: Sequence, cause: Optional[int]) -> None:
         """Ship one burst under the wire lock — foreign requests (sweeps,
         fetches, recover) always see a clean channel between bursts."""
         with self._mu:
@@ -1156,7 +1159,7 @@ class ShippedDeltaReplicator(Replicator):
             if not todo:
                 return
             try:
-                self._ship_window(todo)
+                self._ship_window(todo, cause)
             except (BrokenPipeError, EOFError, OSError):
                 # died mid-ship: nothing past the last ack was consumed;
                 # respawn from a fresh snapshot — the rest of this burst
@@ -1165,7 +1168,7 @@ class ShippedDeltaReplicator(Replicator):
                 self._kill()
                 self._spawn()
 
-    def _ship_window(self, todo: Sequence) -> None:
+    def _ship_window(self, todo: Sequence, cause: Optional[int]) -> None:
         """Encode-and-send with a bounded unacked window. Small consecutive
         chunks coalesce into one D message until ~_COALESCE_TARGET_BYTES of
         encoded payload (tiny per-sync deltas stop paying one round trip
@@ -1183,21 +1186,25 @@ class ShippedDeltaReplicator(Replicator):
                                      or g_bytes < _COALESCE_TARGET_BYTES):
                 c = todo[i]
                 e0 = time.perf_counter()
-                bufs.append(self.encoder.encode_staged(c, self._codec))
+                with tracing.span("wf.encode", cause=cause):
+                    bufs.append(self.encoder.encode_staged(c, self._codec))
                 enc_wall += time.perf_counter() - e0
                 g_bytes += len(bufs[-1])
                 group.append(c)
                 i += 1
             lo, hi = group[0].lo, group[-1].hi
-            self.tr.send_chunks(
-                [b"D" + _DHDR.pack(lo, hi, _PIN_NONE)] + bufs)
+            with tracing.span("wf.send", cause=cause):
+                self.tr.send_chunks(
+                    [b"D" + _DHDR.pack(lo, hi, _PIN_NONE)] + bufs)
             self.messages_sent += 1
             outstanding.append((hi, g_bytes, group))
             while outstanding and (len(outstanding) >= self.window
                                    or self.tr.poll(0)):
-                self._harvest_one(outstanding)
+                with tracing.span("wf.ack", cause=cause):
+                    self._harvest_one(outstanding)
         while outstanding:
-            self._harvest_one(outstanding)
+            with tracing.span("wf.ack", cause=cause):
+                self._harvest_one(outstanding)
         self.encode_wall_s += enc_wall
         self.ship_wall_s += max(time.perf_counter() - t0 - enc_wall, 0.0)
 
@@ -1244,11 +1251,16 @@ class ShippedDeltaReplicator(Replicator):
             return 0
         recs = log.slice(self.offset, hi)
         t0 = time.perf_counter()
-        buf = self.encoder.encode_records(self.offset, hi, recs, self._codec)
+        with tracing.span("wf.encode"):
+            buf = self.encoder.encode_records(self.offset, hi, recs,
+                                              self._codec)
         t1 = time.perf_counter()
         try:
-            reply = self._request(
-                b"D" + _DHDR.pack(self.offset, hi, pin) + buf)
+            with tracing.span("wf.send"):
+                self.tr.send_bytes(
+                    b"D" + _DHDR.pack(self.offset, hi, pin) + buf)
+            with tracing.span("wf.ack"):
+                reply = self._recv_reply()
         except (BrokenPipeError, EOFError, OSError):
             # died mid-ship: nothing past the last ack was consumed; the
             # respawn snapshot covers every un-acked record, so parity is

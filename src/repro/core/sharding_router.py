@@ -56,6 +56,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple, Un
 
 import numpy as np
 
+from repro import tracing
 from repro.core.schema import Status
 from repro.core.steering import Q7_ACT_A, sweep_partials
 from repro.core.store import SnapshotView
@@ -330,7 +331,6 @@ class ShardRouter:
                 thread_name_prefix="shard-scatter") \
             if num_shards > 1 else None
         self.last_scatter_wall_s: List[float] = [0.0] * num_shards
-        self.last_scatter_total_s = 0.0
         self._closed = False
 
     # ------------------------------------------------------------- routing
@@ -387,12 +387,17 @@ class ShardRouter:
         survivors' claim loops never stall on a failed sibling.
         """
         out: Dict[int, Tuple[int, np.ndarray]] = {}
-        for s, sh in enumerate(self.shards):
-            if not sh.alive:
-                continue
-            got = sh.wq.claim_all(k=k, now=now, steal=steal)
-            for lw, rows in got.items():
-                out[int(self.global_worker(s, lw))] = (s, rows)
+        with tracing.span("wf.claim") as sp:
+            for s, sh in enumerate(self.shards):
+                if not sh.alive:
+                    continue
+                got = sh.wq.claim_all(k=k, now=now, steal=steal)
+                for lw, rows in got.items():
+                    out[int(self.global_worker(s, lw))] = (s, rows)
+            if sp:
+                tasks = [int(t) for s, rows in out.values()
+                         for t in self.shards[s].wq.store.col("task_id")[rows]]
+                sp.set(rows=len(tasks), tasks=tasks)
         return out
 
     def ready_counts(self) -> np.ndarray:
@@ -598,13 +603,16 @@ class ShardRouter:
         scatter). Dead shards are skipped exactly as :meth:`compact`
         skips them (their frozen log is the promote WAL), but keep their
         version entry."""
-        versions = self.version_vector()
+        with tracing.span("wf.ship"):
+            versions = self.version_vector()
+            cause = tracing.current()
 
-        def one(s: int) -> None:
-            sh = self.shards[s]
-            if sh.alive and sh.replicator is not None:
-                sh.replicator.sync(upto_version=versions[s])
-        self._scatter_map(one, concurrent_scatter)
+            def one(s: int) -> None:
+                sh = self.shards[s]
+                if sh.alive and sh.replicator is not None:
+                    with tracing.span("wf.ship_shard", cause=cause, shard=s):
+                        sh.replicator.sync(upto_version=versions[s])
+            self._scatter_map(one, concurrent_scatter)
         return versions
 
     def compact(self) -> int:
@@ -792,9 +800,13 @@ class ShardRouter:
         if len(views) != self.num_shards:
             raise ValueError(f"version vector has {len(views)} entries, "
                              f"expected {self.num_shards}")
-        return merge_partials(
-            sweep_partials(v, self.workers_per_shard, now, horizon)
-            for v in views)
+        parts = []
+        for s, v in enumerate(views):
+            with tracing.span("wf.partial", shard=s):
+                parts.append(
+                    sweep_partials(v, self.workers_per_shard, now, horizon))
+        with tracing.span("wf.merge"):
+            return merge_partials(parts)
 
     @staticmethod
     def comparable(result: Dict[str, object]) -> Dict[str, object]:
@@ -865,20 +877,20 @@ class ShardRouter:
                     "replica processes")
         if versions is None:
             versions = self.version_vector()
+        cause = tracing.current()
 
         def one(s: int) -> Tuple[Dict[str, object], float]:
             t0 = time.perf_counter()
             sh = self.shards[s]
-            if sync:
-                sh.replicator.sync(upto_version=versions[s])
-            part = sh.replicator.remote_sweep_partials(
-                now, horizon=horizon,
-                delay_s=0.0 if shard_delay_s is None
-                else float(shard_delay_s[s]))
+            with tracing.span("wf.partial", cause=cause, shard=s):
+                if sync:
+                    sh.replicator.sync(upto_version=versions[s])
+                part = sh.replicator.remote_sweep_partials(
+                    now, horizon=horizon,
+                    delay_s=0.0 if shard_delay_s is None
+                    else float(shard_delay_s[s]))
             return part, time.perf_counter() - t0
-        t0 = time.perf_counter()
         results = self._scatter_map(one, concurrent_scatter)
-        self.last_scatter_total_s = time.perf_counter() - t0
         self.last_scatter_wall_s = [w for _, w in results]
         parts = [p for p, _ in results]
         for s, p in enumerate(parts):
@@ -886,7 +898,8 @@ class ShardRouter:
                 raise RuntimeError(
                     f"shard {s} replica answered the partial sweep at "
                     f"v{p['version']}, expected pinned v{versions[s]}")
-        return merge_partials(parts)
+        with tracing.span("wf.merge"):
+            return merge_partials(parts)
 
     def scatter_spread_s(self) -> float:
         """Straggler signal of the last remote scatter: slowest minus

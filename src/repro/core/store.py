@@ -29,6 +29,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.schema import Column, Status, wq_schema
 
 # Default claim-lease duration (seconds). Lives on the store (not the
@@ -119,7 +120,8 @@ class ColumnStore:
         """Column array safe to mutate: copy-on-write if a snapshot holds it."""
         arr = self.cols[name]
         if not arr.flags.writeable:
-            arr = arr.copy()
+            with tracing.span("wf.cow", column=name, bytes=arr.nbytes):
+                arr = arr.copy()
             self.cols[name] = arr
         return arr
 

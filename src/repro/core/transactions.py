@@ -43,6 +43,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
+
 
 class LogCompactedError(RuntimeError):
     """The requested records were dropped by ``TxnLog.truncate``.
@@ -340,26 +342,27 @@ class TxnLog:
 
     def append(self, op: str, payload: Dict[str, Any],
                store_version: int = -1) -> int:
-        v = self.base + len(self.records)
-        rec = Txn(v, op, _freeze(payload), time.time(), store_version)
-        hot = _HOT_OPS.get(op)
-        if hot is not None:
-            plane = self._planes.get(op)
-            if plane is None:
-                plane = self._planes[op] = _HotPlane(*hot)
-            try:
-                rec.pidx = plane.add(rec.payload)
-                rec.plane = plane
-            except (KeyError, AttributeError, IndexError, TypeError,
-                    ValueError):
-                pass        # raw append with a nonstandard payload: the
+        with tracing.span("wf.log_append"):
+            v = self.base + len(self.records)
+            rec = Txn(v, op, _freeze(payload), time.time(), store_version)
+            hot = _HOT_OPS.get(op)
+            if hot is not None:
+                plane = self._planes.get(op)
+                if plane is None:
+                    plane = self._planes[op] = _HotPlane(*hot)
+                try:
+                    rec.pidx = plane.add(rec.payload)
+                    rec.plane = plane
+                except (KeyError, AttributeError, IndexError, TypeError,
+                        ValueError):
+                    pass    # raw append with a nonstandard payload: the
                             # record replays through the dict path instead
-        self.records.append(rec)
-        if store_version < self._max_store_version:
-            self._monotone = False
-        else:
-            self._max_store_version = store_version
-        return v
+            self.records.append(rec)
+            if store_version < self._max_store_version:
+                self._monotone = False
+            else:
+                self._max_store_version = store_version
+            return v
 
     # ------------------------------------------------------------ consumers
     def register_consumer(self, name: str, offset: Optional[int] = None

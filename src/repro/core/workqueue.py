@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import tracing
 from repro.core.partition import assign_workers, partition_sizes, rehash
 from repro.core.schema import LEGAL_TRANSITIONS, Status
 from repro.core.store import ColumnStore
@@ -319,7 +320,7 @@ class WorkQueue:
         W = self.num_workers
         if k < 1:
             return {w: np.empty(0, np.int64) for w in range(W)}
-        with self.store.txn():
+        with tracing.span("wf.claim") as sp, self.store.txn():
             n = self.store.n_rows
             start = self._scan_start()
             if self.device_claim:
@@ -369,6 +370,9 @@ class WorkQueue:
                                   expires_at=now + self.store.lease_s)
                 self._append_log("claim_all", {"n": len(rows_all),
                                                "rows": rows_all, "now": now})
+            if sp:
+                sp.set(rows=len(rows_all),
+                       tasks=self.store.col("task_id")[rows_all].tolist())
         return out
 
     def _primary_host(self, start: int, k: int
@@ -584,21 +588,24 @@ class WorkQueue:
     # ------------------------------------------------------------- complete
     def finish(self, idx: np.ndarray, *, now: float = 0.0,
                domain_out: Optional[np.ndarray] = None) -> None:
-        self._check_transition(idx, Status.FINISHED)
-        with self.store.txn():
-            # finishing IS the lease renewal for the terminal hop: a worker
-            # that reports a result proves liveness at `now`
-            upd = {"status": int(Status.FINISHED), "end_time": now,
-                   "heartbeat_at": now}
-            self.store.update(np.asarray(idx), **upd)
-            payload = {"ids": np.asarray(idx), "rows": np.asarray(idx),
-                       "now": now}
-            if domain_out is not None:
-                cols = {f"out{i}": domain_out[:, i]
-                        for i in range(domain_out.shape[1])}
-                self.store.update(np.asarray(idx), **cols)
-                payload["domain_out"] = np.asarray(domain_out)
-            self._append_log("finish", payload)
+        with tracing.span("wf.commit") as sp:
+            self._check_transition(idx, Status.FINISHED)
+            with self.store.txn():
+                # finishing IS the lease renewal for the terminal hop: a worker
+                # that reports a result proves liveness at `now`
+                upd = {"status": int(Status.FINISHED), "end_time": now,
+                       "heartbeat_at": now}
+                self.store.update(np.asarray(idx), **upd)
+                payload = {"ids": np.asarray(idx), "rows": np.asarray(idx),
+                           "now": now}
+                if domain_out is not None:
+                    cols = {f"out{i}": domain_out[:, i]
+                            for i in range(domain_out.shape[1])}
+                    self.store.update(np.asarray(idx), **cols)
+                    payload["domain_out"] = np.asarray(domain_out)
+                self._append_log("finish", payload)
+            if sp:
+                sp.set(tasks=self.store.col("task_id")[idx].tolist())
 
     def fail(self, idx: np.ndarray, *, now: float = 0.0,
              max_trials: int = 3) -> None:

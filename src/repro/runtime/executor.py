@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.configs.risers_workflow import WorkflowConfig
 from repro.core.replication import make_replicator
@@ -148,13 +149,19 @@ class TrainExecutor:
     # ---------------------------------------------------------------- tick
     def tick(self) -> Dict[str, float]:
         """One scheduler tick: claim -> execute -> commit provenance."""
+        with tracing.span("wf.tick"):
+            return self._tick()
+
+    def _tick(self) -> Dict[str, float]:
         now = time.time()
         if self.router is not None:
             # any drained shard refills from the richest sibling BEFORE
             # claiming — the cross-shard stealing path
-            if (self.router.ready_counts()
-                    .reshape(self.router.num_shards, -1).sum(1) == 0).any():
-                self.router.rebalance(now=now)
+            with tracing.span("wf.rebalance"):
+                if (self.router.ready_counts()
+                        .reshape(self.router.num_shards, -1).sum(1)
+                        == 0).any():
+                    self.router.rebalance(now=now)
             claims = [(self.router.shards[s].wq, rows)
                       for s, rows in self.router.claim_all(
                           k=1, now=now).values()]
@@ -164,15 +171,20 @@ class TrainExecutor:
         metrics_out: Dict[str, float] = {}
         for wq, rows in claims:
             for row in rows:
-                lr_scale = wq.store.col("in0")[row]
-                shard = int(wq.store.col("in1")[row])
-                batch = batch_for(self.cfg, self.data_cfg, shard)
-                knobs = {"lr": jnp.asarray(self.base_lr * lr_scale,
-                                           jnp.float32)}
+                task = int(wq.store.col("task_id")[row])
+                with tracing.span("wf.batch", task=task):
+                    lr_scale = wq.store.col("in0")[row]
+                    shard = int(wq.store.col("in1")[row])
+                    batch = batch_for(self.cfg, self.data_cfg, shard)
+                    knobs = {"lr": jnp.asarray(self.base_lr * lr_scale,
+                                               jnp.float32)}
                 t0 = time.time()
-                self.state, metrics = self.step_fn(self.state, batch, knobs)
-                loss = float(metrics["loss"])
-                gnorm = float(metrics["grad_norm"])
+                with tracing.span("wf.dispatch", task=task):
+                    self.state, metrics = self.step_fn(self.state, batch,
+                                                       knobs)
+                with tracing.span("wf.sync", task=task):
+                    loss = float(metrics["loss"])
+                    gnorm = float(metrics["grad_norm"])
                 dt_s = time.time() - t0
                 wq.finish(np.asarray([row]), now=time.time(),
                           domain_out=np.asarray(
@@ -184,15 +196,17 @@ class TrainExecutor:
                 metrics_out = rec
         if self.checkpointer and self.checkpoint_every \
                 and self.step and self.step % self.checkpoint_every == 0:
-            if self.router is not None:
-                self.router.sync_secondaries()
-                self.checkpointer.save(self.step, self.state,
-                                       router=self.router)
-            else:
-                self.checkpointer.save(self.step, self.state, self.wq)
+            with tracing.span("wf.checkpoint"):
+                if self.router is not None:
+                    self.router.sync_secondaries()
+                    self.checkpointer.save(self.step, self.state,
+                                           router=self.router)
+                else:
+                    self.checkpointer.save(self.step, self.state, self.wq)
             self._maybe_compact_log()
         if self._steer_future is not None and self._steer_future.done():
-            self.last_steering = self._steer_future.result()
+            with tracing.span("wf.harvest"):
+                self.last_steering = self._steer_future.result()
             metrics_out["steering"] = self.last_steering
             self._steer_future = None
         if self.steer_every and self.step % self.steer_every == 0 \
@@ -201,55 +215,66 @@ class TrainExecutor:
             # expired RUNNING claim (data-plane dead-worker recovery) before
             # analyzing, so the sweep sees the recovered backlog — sharded
             # runs reap per shard and the reclaimed rows feed rebalance
-            self.reaped_total += self.reap(now=time.time())
-            if self.router is not None:
-                # scatter-gather sweep: pin a consistent version vector on
-                # THIS thread (at this tick's commits), merge on the
-                # analyst thread; "remote" scatters the sweep into the
-                # per-shard replica processes instead
-                if self.analyst == "remote":
-                    # pin + ship on THIS (producer) thread — sync_replicas
-                    # settles every shard's replica exactly at this tick's
-                    # version vector — then scatter the partial sweeps into
-                    # the per-shard replica processes from the analyst
-                    # thread (sync=False: only log-free sweep requests ride
-                    # the pipes, so the producer keeps claiming meanwhile)
-                    vec = self.router.sync_replicas()
-                    self._steer_future = self._steer_pool.submit(
-                        self.router.remote_sweep, time.time(),
-                        versions=vec, sync=False)
-                else:
-                    views = (self.router.replica_vector()
-                             if self.analyst == "replica"
-                             else self.router.snapshot_vector())
-                    self._steer_future = self._steer_pool.submit(
-                        self.router.run_all, time.time(), views)
-                return metrics_out
-            if self.replica is not None:
-                # catch the replica up to this tick's commits (O(delta)
-                # wire ship for "remote", in-process log replay for
-                # "replica"); the sync acked the replica's consumer
-                # offset, so compaction piggybacks once a durable
-                # checkpoint anchors history
-                self.replica.sync()
-                self._maybe_compact_log()
-            if self.analyst == "remote":
-                # run the sweep IN the replica process: the analyst thread
-                # only waits on the result pipe — no store array, live or
-                # copied, crosses back
-                self._steer_future = self._steer_pool.submit(
-                    self.replica.remote_sweep, time.time())
-            else:
-                # replica: sweep the caught-up shadow store — the live
-                # arrays are never handed to the analyst thread at all.
-                # snapshot: COW view of the live store at this tick's
-                # commits, analyzed while the next ticks keep claiming
-                view = self.replica.snapshot_view() \
-                    if self.replica is not None \
-                    else self.wq.store.snapshot_view()
-                self._steer_future = self._steer_pool.submit(
-                    self.steering.run_all, time.time(), view)
+            with tracing.span("wf.reap"):
+                self.reaped_total += self.reap(now=time.time())
+            with tracing.span("wf.steer_submit"):
+                self._steer_submit()
         return metrics_out
+
+    def _steer_submit(self) -> None:
+        """Hand this tick's sweep to the analyst thread: cut what it reads
+        here (snapshot, replica catch-up or version vector), run it there."""
+        if self.router is not None:
+            # scatter-gather sweep: pin a consistent version vector on
+            # THIS thread (at this tick's commits), merge on the
+            # analyst thread; "remote" scatters the sweep into the
+            # per-shard replica processes instead
+            if self.analyst == "remote":
+                # pin + ship on THIS (producer) thread — sync_replicas
+                # settles every shard's replica exactly at this tick's
+                # version vector — then scatter the partial sweeps into
+                # the per-shard replica processes from the analyst
+                # thread (sync=False: only log-free sweep requests ride
+                # the pipes, so the producer keeps claiming meanwhile)
+                vec = self.router.sync_replicas()
+                self._steer_future = self._steer_pool.submit(
+                    tracing.handoff(self.router.remote_sweep, "wf.sweep"),
+                    time.time(), versions=vec, sync=False)
+            else:
+                views = (self.router.replica_vector()
+                         if self.analyst == "replica"
+                         else self.router.snapshot_vector())
+                self._steer_future = self._steer_pool.submit(
+                    tracing.handoff(self.router.run_all, "wf.sweep"),
+                    time.time(), views)
+            return
+        if self.replica is not None:
+            # catch the replica up to this tick's commits (O(delta)
+            # wire ship for "remote", in-process log replay for
+            # "replica"); the sync acked the replica's consumer
+            # offset, so compaction piggybacks once a durable
+            # checkpoint anchors history
+            with tracing.span("wf.ship"):
+                self.replica.sync()
+            self._maybe_compact_log()
+        if self.analyst == "remote":
+            # run the sweep IN the replica process: the analyst thread
+            # only waits on the result pipe — no store array, live or
+            # copied, crosses back
+            self._steer_future = self._steer_pool.submit(
+                tracing.handoff(self.replica.remote_sweep, "wf.sweep"),
+                time.time())
+        else:
+            # replica: sweep the caught-up shadow store — the live
+            # arrays are never handed to the analyst thread at all.
+            # snapshot: COW view of the live store at this tick's
+            # commits, analyzed while the next ticks keep claiming
+            view = self.replica.snapshot_view() \
+                if self.replica is not None \
+                else self.wq.store.snapshot_view()
+            self._steer_future = self._steer_pool.submit(
+                tracing.handoff(self.steering.run_all, "wf.sweep"),
+                time.time(), view)
 
     def _maybe_compact_log(self) -> None:
         """Compact the txn log only once a DURABLE checkpoint has acked an
@@ -258,13 +283,14 @@ class TrainExecutor:
         (base snapshot = the checkpoint). Without a checkpoint consumer the
         log is left whole — genesis time-travel stays available and memory
         is bounded by the caller's own `wq.compact_log()` policy instead."""
-        if self.router is not None:
-            for sh in self.router.shards:
-                if sh.alive and sh.wq.log.has_consumer("checkpointer"):
-                    sh.wq.compact_log()
-            return
-        if self.wq.log.has_consumer("checkpointer"):
-            self.wq.compact_log()
+        with tracing.span("wf.compact"):
+            if self.router is not None:
+                for sh in self.router.shards:
+                    if sh.alive and sh.wq.log.has_consumer("checkpointer"):
+                        sh.wq.compact_log()
+                return
+            if self.wq.log.has_consumer("checkpointer"):
+                self.wq.compact_log()
 
     def run(self, max_ticks: int = 10_000) -> List[Dict[str, float]]:
         for _ in range(max_ticks):
