@@ -9,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 import json
 from dataclasses import dataclass, field
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Tuple, Union
 
 # ---------------------------------------------------------------------------
 # Model families
@@ -91,7 +91,11 @@ class ModelConfig:
     dtype: str = "bfloat16"         # compute dtype
     param_dtype: str = "float32"    # master params ("bfloat16" for >=100B)
     optimizer: str = "adamw"        # "adafactor" for the >100B archs
-    remat: bool = True
+    # True: each layer's forward is recomputed in the backward pass; "dots":
+    # the outputs of products without batch dimensions (the projections) are
+    # kept, and the rest (elementwise work, attention's batched products) is
+    # recomputed; False: every residual is kept
+    remat: Union[bool, str] = True
     fsdp: bool = False              # additionally shard params over the data axis
     pipeline_stages: int = 1
     # source annotation

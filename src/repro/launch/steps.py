@@ -126,6 +126,86 @@ def jit_train_step(cfg: ModelConfig):
     return jax.jit(make_train_step(cfg), donate_argnums=(0,))
 
 
+# The stored-residual step must fit with this share of (state + residuals)
+# again on top, for its other temporaries: the f32 gradient tree, the
+# optimizer's fusions and the cotangents of the backward pass.
+RESIDUAL_MARGIN = 0.25
+
+
+def plan_residuals(residual_bytes: int, state_bytes: int,
+                   bytes_limit: Optional[int]) -> Optional[str]:
+    """"stored" when the donated state and the stored step's residuals,
+    with ``RESIDUAL_MARGIN`` on top, fit in ``bytes_limit``; "recomputed"
+    when they do not; None when no limit is known."""
+    if not bytes_limit:
+        return None
+    need = (state_bytes + residual_bytes) * (1 + RESIDUAL_MARGIN)
+    return "stored" if need <= bytes_limit else "recomputed"
+
+
+@dataclasses.dataclass(frozen=True)
+class StepPlan:
+    """How the executor's step treats the forward pass's residuals."""
+    residuals: str              # "stored" | "recomputed"
+    residual_bytes: int         # what the stored step keeps for the backward
+    state_bytes: int            # the donated params + optimizer state
+    bytes_limit: Optional[int]  # the device's, None where it reports none
+    cfg: ModelConfig            # what ``jit_train_step`` is built from
+
+
+def _nbytes(tree) -> int:
+    return sum(x.size * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
+
+
+def device_bytes_limit(device=None) -> Optional[int]:
+    """The device's allocatable bytes; None where the backend reports none
+    (the CPU)."""
+    stats = (device or jax.devices()[0]).memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def plan_train_step(cfg: ModelConfig, batch,
+                    bytes_limit: Optional[int]) -> StepPlan:
+    """Plan the single-device step at ``batch``'s shapes (arrays or
+    ShapeDtypeStructs) from shapes alone: no compile, no allocation.
+
+    The stored step keeps every projection's output (``remat="dots"``) and
+    recomputes only elementwise work and attention's batched products, which
+    on a v5e costs less than moving those residuals through HBM; the
+    recomputed step recomputes each layer's forward (``remat=True``). The
+    residual bytes are the leaves of the vjp of the stored step's loss, for
+    one microbatch. Where no limit is known the configuration's ``remat``
+    stands."""
+    stored = dataclasses.replace(cfg, remat="dots")
+    model = build_model(stored)
+    dt = jnp.dtype(cfg.dtype)
+    mb = max(1, cfg.microbatches)
+    state = abstract_train_state(cfg)
+
+    def residuals(params, mbatch):
+        def loss(p):
+            return model.train_loss(_cast_tree(p, dt), mbatch)[0]
+        return jax.tree.leaves(jax.vjp(loss, params)[1])
+
+    def one_micro(b):
+        return jax.tree.map(lambda x: x[0], _split_micro(b, mb))
+
+    micro = jax.eval_shape(one_micro, batch)
+    res = _nbytes(jax.eval_shape(residuals, state["params"], micro))
+    state_bytes = _nbytes(state)
+    choice = plan_residuals(res, state_bytes, bytes_limit)
+    if choice is None:
+        choice = "recomputed" if cfg.remat is True else "stored"
+        planned = cfg
+    else:
+        planned = stored if choice == "stored" else \
+            dataclasses.replace(cfg, remat=True)
+    return StepPlan(residuals=choice, residual_bytes=res,
+                    state_bytes=state_bytes, bytes_limit=bytes_limit,
+                    cfg=planned)
+
+
 def init_train_state(cfg: ModelConfig, rng, grad_compression: bool = False):
     model = build_model(cfg)
     params = model.init(rng)

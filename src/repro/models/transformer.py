@@ -150,7 +150,11 @@ def _maybe_ckpt(cfg: ModelConfig, fn):
     # prevent_cse=False: safe under scan (which already isolates iterations)
     # and lets XLA keep the bf16 carry as the saved residual instead of an
     # upcast f32 copy (halves per-layer activation stash)
-    return jax.checkpoint(fn, prevent_cse=False) if cfg.remat else fn
+    if not cfg.remat:
+        return fn
+    policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+              if cfg.remat == "dots" else None)
+    return jax.checkpoint(fn, prevent_cse=False, policy=policy)
 
 
 def _run_stack(cfg: ModelConfig, params: Params, x, *, positions,
@@ -283,7 +287,8 @@ def chunked_xent(cfg: ModelConfig, x: jax.Array, table: jax.Array,
         gold = jnp.take_along_axis(logits, li[..., None], axis=-1)[..., 0]
         return tot + jnp.sum(logz - gold), None
 
-    body = jax.checkpoint(body)
+    if nc > 1:      # one chunk: recomputing it would save no memory
+        body = jax.checkpoint(body)
     tot, _ = _flags_scan(body, jnp.zeros((), jnp.float32),
                           (jnp.moveaxis(xc, 1, 0), jnp.moveaxis(lc, 1, 0)))
     return tot / (b * s)

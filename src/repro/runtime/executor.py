@@ -32,8 +32,8 @@ from repro.core.steering import SteeringEngine
 from repro.core.supervisor import SecondarySupervisor, Supervisor
 from repro.core.workqueue import WorkQueue
 from repro.data.pipeline import DataConfig, batch_for
-from repro.launch.steps import init_train_state, jit_train_step, \
-    make_serve_step
+from repro.launch.steps import device_bytes_limit, init_train_state, \
+    jit_train_step, make_serve_step, plan_train_step
 from repro.models.registry import build_model
 
 
@@ -127,7 +127,11 @@ class TrainExecutor:
             max_workers=1, thread_name_prefix="steering")
         self._steer_future: Optional[concurrent.futures.Future] = None
         self.last_steering: Optional[Dict[str, object]] = None
-        self.step_fn = jit_train_step(cfg)
+        # store the forward's residuals when they fit on the device, else
+        # recompute them in the backward pass (from shapes, nothing runs)
+        self.step_plan = plan_train_step(
+            cfg, batch_for(cfg, self.data_cfg, 0), device_bytes_limit())
+        self.step_fn = jit_train_step(self.step_plan.cfg)
         self.state = init_train_state(cfg, jax.random.PRNGKey(seed))
         self.step = 0
         self.reaped_total = 0
@@ -179,7 +183,9 @@ class TrainExecutor:
                     knobs = {"lr": jnp.asarray(self.base_lr * lr_scale,
                                                jnp.float32)}
                 t0 = time.time()
-                with tracing.span("wf.dispatch", task=task):
+                with tracing.span("wf.dispatch", task=task,
+                                  residuals=self.step_plan.residuals,
+                                  residual_bytes=self.step_plan.residual_bytes):
                     self.state, metrics = self.step_fn(self.state, batch,
                                                        knobs)
                 with tracing.span("wf.sync", task=task):

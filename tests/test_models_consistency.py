@@ -118,3 +118,138 @@ def test_grad_compression_roundtrip_small_error():
     # error feedback: accumulated compressed grads track the exact sum
     rel = float(jnp.linalg.norm(total - exact) / jnp.linalg.norm(exact))
     assert rel < 0.01
+
+
+# --------------------------------------------------------------------------
+# residuals: stored (no rematerialisation) against recomputed
+# --------------------------------------------------------------------------
+def _step_batch(cfg, b=4, s=32):
+    return {"tokens": jax.random.randint(jax.random.PRNGKey(0), (b, s),
+                                         0, cfg.vocab_size),
+            "labels": jax.random.randint(jax.random.PRNGKey(1), (b, s),
+                                         0, cfg.vocab_size)}
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-1.3b"])
+def test_stored_and_recomputed_residuals_give_the_same_step(arch):
+    """The plan changes where the backward pass reads its activations from,
+    not what it computes: loss, grad norm and updated params agree to f32
+    round-off."""
+    from repro.launch.steps import (init_train_state, jit_train_step,
+                                    plan_train_step)
+    cfg = smoke_config(arch)
+    batch = _step_batch(cfg)
+    plans = {name: plan_train_step(cfg, batch, limit)
+             for name, limit in (("stored", 1 << 40), ("recomputed", 1))}
+    assert {n: p.residuals for n, p in plans.items()} == \
+        {"stored": "stored", "recomputed": "recomputed"}
+    assert plans["stored"].residual_bytes == \
+        plans["recomputed"].residual_bytes > 0
+    out = {}
+    for name, plan in plans.items():
+        assert plan.cfg.remat == {"stored": "dots", "recomputed": True}[name]
+        state = init_train_state(plan.cfg, jax.random.PRNGKey(2))
+        out[name] = jit_train_step(plan.cfg)(state, batch,
+                                             {"lr": jnp.float32(1e-3)})
+    (s_st, m_st), (s_re, m_re) = out["stored"], out["recomputed"]
+    assert float(m_st["loss"]) == pytest.approx(float(m_re["loss"]),
+                                                rel=1e-6)
+    assert float(m_st["grad_norm"]) == pytest.approx(
+        float(m_re["grad_norm"]), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(s_st), jax.tree.leaves(s_re)):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+
+
+def _plain_xent(x, table, labels):
+    logits = (x @ table.T).astype(jnp.float32)
+    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold)
+
+
+def test_one_chunk_xent_matches_the_checkpointed_loss():
+    """At one chunk the loss body is not rematerialised; value and
+    gradients equal the same loss under ``jax.checkpoint``."""
+    cfg = smoke_config("qwen2-0.5b")
+    b, s = 2, 16
+    assert s <= cfg.loss_chunk                   # one chunk
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, cfg.d_model))
+    table = jax.random.normal(jax.random.PRNGKey(1),
+                              (cfg.vocab_size, cfg.d_model)) * 0.05
+    labels = jax.random.randint(jax.random.PRNGKey(2), (b, s), 0,
+                                cfg.vocab_size)
+    got, (gx, gt) = jax.value_and_grad(
+        lambda x, t: T.chunked_xent(cfg, x, t, labels), (0, 1))(x, table)
+    want, (wx, wt) = jax.value_and_grad(
+        jax.checkpoint(lambda x, t: _plain_xent(x, t, labels)),
+        (0, 1))(x, table)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    assert float(jnp.max(jnp.abs(gx - wx))) < 1e-6
+    assert float(jnp.max(jnp.abs(gt - wt))) < 1e-6
+
+
+@pytest.mark.parametrize("s,remat", [(16, False), (64, True)])
+def test_xent_checkpoints_only_past_one_chunk(s, remat):
+    cfg = dataclasses.replace(smoke_config("qwen2-0.5b"), loss_chunk=16)
+    x = jnp.zeros((2, s, cfg.d_model))
+    table = jnp.zeros((cfg.vocab_size, cfg.d_model))
+    labels = jnp.zeros((2, s), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda x: T.chunked_xent(cfg, x, table, labels)))(x))
+    assert ("remat" in jaxpr or "checkpoint" in jaxpr) == remat
+
+
+@pytest.mark.parametrize("res,state,limit,want", [
+    (3, 5, 10, "stored"),           # (3 + 5) x 1.25 = 10: fits exactly
+    (4, 5, 10, "recomputed"),       # 11.25 over the limit
+    (0, 9, 10, "recomputed"),       # the state alone leaves no margin
+    (3, 5, None, None),             # no limit reported: no choice made
+    (3, 5, 0, None),
+])
+def test_plan_residuals_on_explicit_budgets(res, state, limit, want):
+    from repro.launch.steps import RESIDUAL_MARGIN, plan_residuals
+    assert RESIDUAL_MARGIN == 0.25
+    assert plan_residuals(res, state, limit) == want
+
+
+@pytest.mark.parametrize("remat", [True, False, "dots"])
+def test_plan_without_a_limit_keeps_the_configuration(remat):
+    from repro.launch.steps import plan_train_step
+    cfg = dataclasses.replace(smoke_config("qwen2-0.5b"), remat=remat)
+    plan = plan_train_step(cfg, _step_batch(cfg), None)
+    assert plan.cfg is cfg
+    assert plan.residuals == ("recomputed" if remat is True else "stored")
+    assert plan.bytes_limit is None and plan.state_bytes > 0
+
+
+def projections(cfg, batch) -> int:
+    """Matrix products without batch dimensions in the lowered train step:
+    the layers' projections, each counted again where it is recomputed."""
+    from repro.launch.steps import abstract_train_state, make_train_step
+    knobs = {"lr": jax.ShapeDtypeStruct((), jnp.float32)}
+    txt = jax.jit(make_train_step(cfg)).lower(
+        abstract_train_state(cfg), batch, knobs).as_text()
+    return sum("dot_general" in line and "batching_dims" not in line
+               for line in txt.splitlines())
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-1.3b"])
+def test_stored_plan_recomputes_no_projection(arch):
+    """The stored step keeps every projection's output: it lowers with as
+    many unbatched products as a step that recomputes nothing, and fewer
+    than one that recomputes each layer."""
+    cfg = smoke_config(arch)
+    batch = jax.eval_shape(lambda: _step_batch(cfg))
+    n = {r: projections(dataclasses.replace(cfg, remat=r), batch)
+         for r in (False, "dots", True)}
+    assert n["dots"] == n[False] < n[True], n
+
+
+def test_plan_counts_the_residuals_of_one_microbatch():
+    from repro.launch.steps import plan_train_step
+    cfg = smoke_config("qwen2-0.5b")
+    batch = _step_batch(cfg, b=8)
+    one = plan_train_step(cfg, batch, None).residual_bytes
+    four = plan_train_step(dataclasses.replace(cfg, microbatches=4), batch,
+                           None).residual_bytes
+    assert four < one

@@ -22,7 +22,8 @@ from repro.configs import get_config
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.wq_claim.ops import wq_claim
-from repro.launch.steps import abstract_train_state, jit_train_step
+from repro.launch.steps import (RESIDUAL_MARGIN, abstract_train_state,
+                                jit_train_step, plan_train_step)
 
 HBM_BYTES = 16 * 2 ** 30            # one TPU v5e chip
 QWEN = get_config("qwen2-0.5b")
@@ -76,15 +77,26 @@ def test_attention_kernels_compile_for_v5e(one_chip):
 
 
 def test_qwen2_train_step_fits_one_v5e(one_chip):
-    """The executor's step (donated state) at its default 8 x 128 batch."""
+    """The executor's step (donated state) at its default 8 x 128 batch,
+    built with the plan one v5e gets: every projection's output stored, none
+    recomputed, and the compiled total inside the planner's estimate."""
     state = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
                          abstract_train_state(QWEN))
     batch = {"tokens": _sds((8, 128), jnp.int32, one_chip),
              "labels": _sds((8, 128), jnp.int32, one_chip)}
     knobs = {"lr": _sds((), jnp.float32, one_chip)}
-    mem = jit_train_step(QWEN).lower(state, batch, knobs).compile() \
-        .memory_analysis()
+    plan = plan_train_step(QWEN, batch, HBM_BYTES)
+    assert plan.residuals == "stored" and plan.cfg.remat == "dots"
+    lowered = jit_train_step(plan.cfg).lower(state, batch, knobs)
+    # the scan body's seven projections and the LM head: each once forward
+    # and twice backward (input and weight gradients), none recomputed
+    products = sum("dot_general" in line and "batching_dims" not in line
+                   for line in lowered.as_text().splitlines())
+    assert products == 3 * 8, products
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes > 0          # the state is donated
-    assert total < HBM_BYTES, mem
+    planned = (plan.state_bytes + plan.residual_bytes) * (1 + RESIDUAL_MARGIN)
+    assert total <= planned <= HBM_BYTES, (mem, plan)

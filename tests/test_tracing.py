@@ -173,6 +173,9 @@ def test_one_tick_records_each_claimed_task(shards):
         got = sorted(s.attrs["task"] for s in by_name(spans, name))
         assert got == sorted(tasks), name
         assert all(s.parent == tick.id for s in by_name(spans, name))
+    for s in by_name(spans, "wf.dispatch"):     # the step's plan, per step
+        assert s.attrs["residuals"] == ex.step_plan.residuals == "stored"
+        assert s.attrs["residual_bytes"] == ex.step_plan.residual_bytes > 0
     commits = by_name(spans, "wf.commit")
     assert sorted(t for s in commits for t in s.attrs["tasks"]) \
         == sorted(tasks)
@@ -192,3 +195,34 @@ def test_one_tick_records_each_claimed_task(shards):
             if by_id[s.parent].name == "wf.claim"]
     assert len(cows) == shards * len(CLAIM_COLUMNS)
     assert {s.attrs["column"] for s in cows} == CLAIM_COLUMNS
+
+
+@pytest.mark.parametrize("limit,want,remat", [
+    (None, "stored", False), (1 << 40, "stored", "dots"),
+    (1 << 20, "recomputed", True)])
+def test_dispatch_spans_carry_the_planned_residuals(monkeypatch, limit, want,
+                                                    remat):
+    """The executor plans its step from the device's reported limit (none on
+    the CPU: the configuration's ``remat`` stands) and every dispatched
+    step's span says which plan ran."""
+    import repro.runtime.executor as executor
+    monkeypatch.setattr(executor, "device_bytes_limit", lambda: limit)
+    cfg = smoke_config("qwen2-0.5b")            # remat off
+    ex = TrainExecutor(cfg, num_workers=2,
+                       data_cfg=DataConfig(vocab_size=cfg.vocab_size,
+                                           seq_len=16, batch_size=2))
+    try:
+        assert ex.step_plan.residuals == want
+        assert ex.step_plan.cfg.remat == remat
+        assert ex.step_plan.bytes_limit == limit
+        ex.submit_steps(4)
+        tracing.enable()
+        ex.tick()
+        ex.tick()
+    finally:
+        ex.close()
+    spans = by_name(tracing.drain(), "wf.dispatch")
+    assert len(spans) == 4
+    assert {s.attrs["residuals"] for s in spans} == {want}
+    assert {s.attrs["residual_bytes"] for s in spans} == \
+        {ex.step_plan.residual_bytes}
