@@ -58,7 +58,9 @@ def readings(cfg_file, seed: int, control: bool):
     t0 = time.perf_counter()
     traffic = harness.load_traffic("steer-off")
     cfg_file = dict(cfg_file, tasks=64)     # three steps need a few rows
-    ex, rec, _ = harness.build(cfg_file, traffic, seed, harness.Spans())
+    reference = harness.load_reference(cfg_file)
+    ex, rec, _ = harness.build(cfg_file, traffic, seed, harness.Spans(),
+                               reference)
     try:
         while len(rec.step_metrics) < 3:
             ex.tick()
@@ -69,7 +71,8 @@ def readings(cfg_file, seed: int, control: bool):
     ex.state = None
     del ex
     gc.collect()
-    ref = harness.reference_readings(cfg_file, rec, relations, seed)
+    ref = harness.reference_readings(cfg_file, reference, rec, relations,
+                                     seed)
     g1 = ref["grad1"]
     med = float(np.median(np.concatenate([np.ravel(g1[n]) for n in g1])))
     out = [{"kind": "program", "seed": seed,
@@ -83,8 +86,8 @@ def readings(cfg_file, seed: int, control: bool):
         half = int(cfg_file["payload"]["batch_size"]) // 2
         for kind, kw in (("control_fp8", {"quant": "fp8"}),
                          ("fault_half_batch", {"rows": half})):
-            other = harness.reference_readings(cfg_file, rec, relations,
-                                               seed, **kw)
+            other = harness.reference_readings(cfg_file, reference, rec,
+                                               relations, seed, **kw)
             out.append({"kind": kind, "seed": seed,
                         **harness.train_numbers(other, ref),
                         "loss": other["loss"]})

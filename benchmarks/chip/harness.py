@@ -6,6 +6,9 @@ that ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json``: the deployment and its payload's sizes,
   guarantees and correctness limits;
+* ``<reference>.py``, the module that the payload's ``"reference"`` names:
+  the payload's plain reference, with the functions ``REFERENCE_API``
+  lists (``reference.py`` describes them);
 * ``traffic/<mix>.json``: the parameters of the one traffic generator below;
 * ``metrics/<metric>.py``: a reducer ``reduce(run) -> float | None`` over the
   run's spans, counters and device trace (``None``: nothing to read).
@@ -77,16 +80,42 @@ def load_traffic(name: str, base: str = HERE) -> Dict[str, Any]:
     return t
 
 
+def _load_module(prefix: str, name: str, path: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_metric(name: str, base: str = HERE) -> Callable[["RunRecord"],
                                                          Optional[float]]:
     path = os.path.join(base, "metrics", f"{name}.py")
     if not os.path.isfile(path):
         raise BenchError(f"missing metric reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.reduce
+    return _load_module("bench_metric_", name, path).reduce
+
+
+# What a payload's reference module provides, by name.
+REFERENCE_API = ("weight_shapes", "weight_leaf", "make_weights", "leaf_norms",
+                 "train_readings", "program_overrides", "train_step_flops")
+
+
+def load_reference(cfg_file: Dict[str, Any], base: str = HERE):
+    """The payload's plain reference: the module ``<base>/<name>.py`` that
+    the configuration's ``payload["reference"]`` names."""
+    name = cfg_file["payload"].get("reference")
+    if not isinstance(name, str) or os.path.basename(name) != name:
+        raise BenchError(f"configuration {cfg_file.get('name')}: payload "
+                         f"reference {name!r} names no module file")
+    path = os.path.join(base, f"{name}.py")
+    if not os.path.isfile(path):
+        raise BenchError(f"missing reference module {path}")
+    mod = _load_module("bench_reference_", name, path)
+    missing = [f for f in REFERENCE_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise BenchError(f"reference module {path} lacks {missing}")
+    return mod
 
 
 def resolve_cell(bench: Dict[str, Any], workload: str, base: str = HERE
@@ -256,16 +285,30 @@ def from_program_tree(tree) -> Dict[str, Any]:
             for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def model_config(payload: Dict[str, Any]):
-    """The program's ModelConfig for the payload the file describes. The
-    published configuration of ``arch`` is taken as it is, with the sizes
-    of the file laid over it (identical for the published sizes)."""
+def _laid_over(obj, fields: Dict[str, Any]):
+    """The dataclass ``obj`` with ``fields`` laid over it; a dict given for
+    a field that holds a dataclass is laid over that field's value."""
+    known = {f.name for f in dataclasses.fields(obj)}
+    out = {}
+    for k, v in fields.items():
+        if k not in known:
+            raise BenchError(f"{type(obj).__name__} has no field {k!r}")
+        if isinstance(v, dict):
+            inner = getattr(obj, k)
+            if not dataclasses.is_dataclass(inner):
+                raise BenchError(f"{type(obj).__name__}.{k} is {inner!r}, "
+                                 f"no dataclass to lay {sorted(v)} over")
+            v = _laid_over(inner, v)
+        out[k] = v
+    return dataclasses.replace(obj, **out)
+
+
+def model_config(payload: Dict[str, Any], overrides: Dict[str, Any]):
+    """The program's ModelConfig for the payload the file describes: the
+    published configuration of ``arch`` with ``overrides`` (the reference
+    module's ``program_overrides(payload)``) laid over it."""
     from repro.configs import get_config
-    keys = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
-            "d_ff", "vocab_size", "rope_theta", "qkv_bias",
-            "tie_embeddings", "dtype", "param_dtype")
-    cfg = dataclasses.replace(get_config(payload["arch"]),
-                              **{k: payload[k] for k in keys})
+    cfg = _laid_over(get_config(payload["arch"]), overrides)
     if cfg.optimizer != payload["optimizer"]["name"]:
         raise BenchError(f"optimizer {cfg.optimizer} != "
                          f"{payload['optimizer']['name']}")
@@ -285,19 +328,48 @@ class Recorder:
         self.delta: Optional[Dict[str, np.ndarray]] = None
 
 
+def leaf_groups(shapes: Dict[str, Tuple[int, ...]]) -> List[List[str]]:
+    """The leaves in groups of at most the largest leaf's size, filled
+    largest first: few calls, none holding more than about one leaf."""
+    size = {n: math.prod(s) for n, s in shapes.items()}
+    groups: List[List[str]] = []
+    room: List[int] = []
+    for n in sorted(size, key=lambda n: (-size[n], n)):
+        i = next((i for i, r in enumerate(room) if size[n] <= r), None)
+        if i is None:
+            groups.append([])
+            room.append(max(size.values()))
+            i = len(room) - 1
+        groups[i].append(n)
+        room[i] -= size[n]
+    return groups
+
+
+def change_call(ref, payload: Dict[str, Any]) -> Callable:
+    """Jitted ``(leaves, key) ->`` the reference's ``leaf_norms`` of each
+    given leaf's change from its initial value, which is made again from
+    the key. Called once per group of ``leaf_groups``, so that no whole tree
+    is made beside the program's state."""
+    import jax
+
+    def change(xs, key):
+        return ref.leaf_norms({n: x - ref.weight_leaf(payload, key, n)
+                               for n, x in xs.items()})
+    return jax.jit(change)
+
+
 def build(cfg_file: Dict[str, Any], traffic: Dict[str, Any], seed: int,
-          spans: Spans):
-    """Set-up, part one: the executor, the seeded weights, the backlog and
-    the benchmark's wrappers. Returns (executor, recorder, sweep pool)."""
+          spans: Spans, ref):
+    """Set-up, part one: the executor, the seeded weights (made by the
+    payload's reference module ``ref``), the backlog and the benchmark's
+    wrappers. Returns (executor, recorder, sweep pool)."""
     import jax
 
     from repro.data.pipeline import DataConfig
     from repro.runtime.executor import TrainExecutor
 
-    import reference as ref
-
     payload = cfg_file["payload"]
-    cfg = model_config(payload)
+    cfg = model_config(payload, ref.program_overrides(payload))
     seeds = derive_seeds(seed)
     ex = TrainExecutor(
         cfg, num_workers=int(cfg_file["workers"]),
@@ -310,13 +382,17 @@ def build(cfg_file: Dict[str, Any], traffic: Dict[str, Any], seed: int,
         analyst=cfg_file["analyst"], replicas=max(1, int(cfg_file["replicas"])),
         shards=int(cfg_file["shards"]), lease_s=float(cfg_file["lease_s"]))
     key = weights_key(seeds)
-    ex.state = {"params": to_program_tree(ex.state["params"],
-                                          ref.make_weights(payload, key)),
-                "opt": ex.state["opt"]}
+    # the executor's own initial parameters are freed before the benchmark's
+    # are made, so that the two trees never share the chip
+    like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        ex.state["params"])
+    ex.state["params"] = None
+    ex.state["params"] = to_program_tree(like, ref.make_weights(payload, key))
     rec = Recorder()
     b1 = float(payload["optimizer"]["beta1"])
     norms = jax.jit(ref.leaf_norms)
-    sub = jax.jit(lambda a, b: {n: a[n] - b[n] for n in a})
+    groups = leaf_groups(ref.weight_shapes(payload))
+    change = change_call(ref, payload)
 
     def step_after(a, kw, out, t0, t1):
         state, metrics = out
@@ -329,10 +405,9 @@ def build(cfg_file: Dict[str, Any], traffic: Dict[str, Any], seed: int,
             rec.grad1 = {k: np.asarray(v) / (1.0 - b1)
                          for k, v in norms(m).items()}
         if n == 3:      # the parameters' change over the first three steps
-            w0 = ref.make_weights(payload, key)
-            d = sub(from_program_tree(state["params"]), w0)
-            rec.delta = {k: np.asarray(v) for k, v in norms(d).items()}
-            del w0, d
+            params = from_program_tree(state["params"])
+            rec.delta = {k: np.asarray(v) for g in groups for k, v in
+                         change({k: params[k] for k in g}, key).items()}
         rec.step_wall.append(time.time())
     ex.step_fn = spans.wrap("step", ex.step_fn, step_after)
 
@@ -613,12 +688,11 @@ def program_readings(rec: Recorder) -> Dict[str, Any]:
             "grad1": rec.grad1, "delta": rec.delta}
 
 
-def reference_readings(cfg_file, rec: Recorder, relations, seed: int,
+def reference_readings(cfg_file, ref, rec: Recorder, relations, seed: int,
                        quant: Optional[str] = None, rows: Optional[int] = None
                        ) -> Dict[str, Any]:
-    """The plain reference over the first three steps' batches (their first
-    ``rows`` rows only, where given: a planted fault)."""
-    import reference as ref
+    """The plain reference ``ref`` over the first three steps' batches
+    (their first ``rows`` rows only, where given: a planted fault)."""
     from datagen import shard_batch
     p = dict(cfg_file["payload"])
     seeds = derive_seeds(seed)
@@ -692,10 +766,10 @@ def run_cell(resolved: Dict[str, Any], seed: int, seconds: float,
         "bf16_flops_per_s": float("nan")}
     lowerings = _count_lowerings()
 
-    import flops
     cfg_file, traffic = resolved["config"], resolved["traffic"]
+    ref = load_reference(cfg_file, resolved["base"])
     spans = Spans(annotate=trace)
-    ex, rec, pool = build(cfg_file, traffic, seed, spans)
+    ex, rec, pool = build(cfg_file, traffic, seed, spans, ref)
     try:
         warm_up(ex, rec, pool, traffic, spans)
         jax.block_until_ready(ex.state)
@@ -763,7 +837,7 @@ def run_cell(resolved: Dict[str, Any], seed: int, seconds: float,
     ex.state = None
     del ex
     gc.collect()
-    refr = reference_readings(cfg_file, rec, relations, seed)
+    refr = reference_readings(cfg_file, ref, rec, relations, seed)
     put(train_numbers(prog, refr), cfg_file["limits"])
     trace_data = None
     if trace:
@@ -777,7 +851,7 @@ def run_cell(resolved: Dict[str, Any], seed: int, seconds: float,
         tasks_traced=(sum(traced[0] <= rec.finishes[i]["t"] <= traced[1]
                           for i in wsteps) if traced else 0),
         s_per_step=s_per_step, sweeps=sweeps_win,
-        flops_per_task=flops.dense_train_step_flops(cfg_file["payload"]),
+        flops_per_task=float(ref.train_step_flops(cfg_file["payload"])),
         peaks=peaks, chips=chips)
     metrics: Dict[str, Dict[str, Any]] = {}
     if trace:

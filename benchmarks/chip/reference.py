@@ -14,16 +14,26 @@ every forward matrix product to float8_e4m3fn with a per-tensor scale (the
 backward pass sees the rounded operands through a straight-through
 estimator): the step below bfloat16 that a later change might take.
 
-Weights are made here, on the device, in one jitted call from a key: the
-benchmark gives them to the program and to this reference alike.
+Weights are made here, on the device, from a key (``weight_leaf``, one
+leaf, and ``make_weights``, all of them in one jitted call): the benchmark
+gives them to the program and to this reference alike, and makes leaves
+again where it needs their initial values.
+
+The interface the harness loads a payload's reference by (``payload
+["reference"]`` names this file): ``weight_shapes``, ``weight_leaf``,
+``make_weights``, ``leaf_norms``, ``train_readings``, ``program_overrides``
+and ``train_step_flops``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import flops
 
 Params = Dict[str, jax.Array]
 
@@ -46,25 +56,54 @@ def weight_shapes(p: Dict[str, object]) -> Dict[str, Tuple[int, ...]]:
     }
 
 
-def make_weights(p: Dict[str, object], key: jax.Array) -> Params:
-    """Published Qwen2 initialisation: normal(0, initializer_range) for
-    every projection and the embedding, zero biases, unit norm scales.
-    One jitted call, float32, on the default device."""
-    shapes = weight_shapes(p)
-    std = float(p["initializer_range"])
+_NORMS = ("final_norm", "layers.ln1", "layers.ln2")
 
-    def make(key):
-        keys = jax.random.split(key, len(shapes))
-        out = {}
-        for k, (name, shape) in zip(keys, sorted(shapes.items())):
-            if name.endswith(".b"):
-                out[name] = jnp.zeros(shape, jnp.float32)
-            elif name in ("final_norm", "layers.ln1", "layers.ln2"):
-                out[name] = jnp.ones(shape, jnp.float32)
-            else:
-                out[name] = std * jax.random.normal(k, shape, jnp.float32)
-        return out
-    return jax.jit(make)(key)
+
+# jitted, so that a leaf made alone rounds as it does inside make_weights
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _normal(key: jax.Array, index: int, count: int,
+            shape: Tuple[int, ...], std: float) -> jax.Array:
+    return std * jax.random.normal(jax.random.split(key, count)[index],
+                                   shape, jnp.float32)
+
+
+def weight_leaf(p: Dict[str, object], key: jax.Array, name: str) -> jax.Array:
+    """One weight of the published Qwen2 initialisation, float32 on the
+    default device: normal(0, initializer_range) for every projection and
+    the embedding, from the key's ``i``-th split, ``i`` the name's place in
+    sorted order; zero biases; unit norm scales."""
+    shapes = weight_shapes(p)
+    shape = shapes[name]
+    if name.endswith(".b"):
+        return jnp.zeros(shape, jnp.float32)
+    if name in _NORMS:
+        return jnp.ones(shape, jnp.float32)
+    return _normal(key, sorted(shapes).index(name), len(shapes), shape,
+                   float(p["initializer_range"]))
+
+
+def make_weights(p: Dict[str, object], key: jax.Array) -> Params:
+    """Every weight in one jitted call: the dict of ``weight_leaf`` over
+    ``weight_shapes``."""
+    names = sorted(weight_shapes(p))
+    return jax.jit(lambda k: {n: weight_leaf(p, k, n) for n in names})(key)
+
+
+# the program's ModelConfig fields that the payload sets
+_PROGRAM_FIELDS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
+                   "head_dim", "d_ff", "vocab_size", "rope_theta", "qkv_bias",
+                   "tie_embeddings", "dtype", "param_dtype")
+
+
+def program_overrides(p: Dict[str, object]) -> Dict[str, object]:
+    """The fields of the program's configuration that the payload sets,
+    by name, laid over the published configuration of ``p["arch"]``."""
+    return {k: p[k] for k in _PROGRAM_FIELDS}
+
+
+def train_step_flops(p: Dict[str, object]) -> float:
+    """Analytic FLOPs of one task's train step (``flops.py``)."""
+    return flops.dense_train_step_flops(p)
 
 
 def _fake_fp8(x: jax.Array) -> jax.Array:

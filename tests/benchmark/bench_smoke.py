@@ -30,3 +30,19 @@ def smoke_base(tmp, tasks=400, dtype="float32", size=None):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     return base, copy.deepcopy(bench)
+
+
+def renamed_reference(base, module, config="stream-qwen2-0.5b", name=None):
+    """A copy of ``reference.py`` in ``base`` as ``<module>.py``, and a
+    configuration ``name`` (default ``<config>-<module>``), otherwise a copy
+    of ``config``, whose payload names it. Returns the new name."""
+    name = name or f"{config}-{module}"
+    shutil.copy(os.path.join(base, "reference.py"),
+                os.path.join(base, f"{module}.py"))
+    with open(os.path.join(base, "configs", f"{config}.json")) as f:
+        c = json.load(f)
+    c["name"] = name
+    c["payload"]["reference"] = module
+    with open(os.path.join(base, "configs", f"{name}.json"), "w") as f:
+        json.dump(c, f)
+    return name

@@ -20,7 +20,6 @@ import pytest
 import bench_smoke
 import calibrate
 import harness
-import reference
 
 SIZE = {"num_layers": 4, "d_model": 256, "num_heads": 4, "num_kv_heads": 2,
         "head_dim": 64, "d_ff": 1024, "vocab_size": 8192, "seq_len": 64,
@@ -53,7 +52,7 @@ def test_control_and_fault_fail(readings, kind):
     assert _failed(r[kind], limits), r[kind]
 
 
-def _fp8_step(payload):
+def _fp8_step(reference, payload):
     """The program's train step replaced by the reference's AdamW step with
     fp8 forward matrix products, on the program's own state tree."""
     def make(cfg):
@@ -83,8 +82,9 @@ def test_control_in_the_programs_place_reads_incorrect(tmp_path,
                                          dtype="float32", size=SIZE)
     resolved = harness.resolve_cell(bench, "stream-qwen2-0.5b.steer-off",
                                     base)
-    monkeypatch.setattr(executor, "jit_train_step",
-                        _fp8_step(resolved["config"]["payload"]))
+    cfg = resolved["config"]
+    monkeypatch.setattr(executor, "jit_train_step", _fp8_step(
+        harness.load_reference(cfg, base), cfg["payload"]))
     out = harness.run_cell(resolved, SEED, 0.5, False, time.perf_counter(),
                            require_platform=None)
     failed = [k for k, c in out["checks"].items()

@@ -22,11 +22,16 @@ def test_flops_agree_with_the_roofline_model_for_qwen2_at_8x128():
 
 
 def test_every_configuration_runs_the_published_widths():
+    """Through the payload's reference module: the program's configuration
+    and the FLOP count that ``train_step.mfu`` divides by."""
     from repro.configs import get_config
     for name in ("stream-qwen2-0.5b", "sharded4-qwen2-0.5b"):
-        p = harness.load_config(name)["payload"]
-        cfg = harness.model_config(p)
-        assert cfg == get_config(p["arch"])
+        cfg_file = harness.load_config(name)
+        p = cfg_file["payload"]
+        ref = harness.load_reference(cfg_file)
+        cfg = harness.model_config(p, ref.program_overrides(p))
+        assert cfg == get_config("qwen2-0.5b")
+        assert ref.train_step_flops(p) == 3052073385984.0
 
 
 def test_mfu_reader_is_a_share_of_peak():

@@ -15,11 +15,8 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 
 def test_new_config_mix_and_metric_are_found_from_their_own_files(tmp_path):
     base, bench = bench_smoke.smoke_base(tmp_path)
-    cfg = harness.load_config("stream-qwen2-0.5b", base)
-    cfg["name"] = "another-deployment"
-    with open(os.path.join(base, "configs", "another-deployment.json"),
-              "w") as f:
-        json.dump(cfg, f)
+    bench_smoke.renamed_reference(base, "another_payload",
+                                  name="another-deployment")
     with open(os.path.join(base, "traffic", "steer-every-4.json"), "w") as f:
         json.dump(dict(harness.load_traffic("steer-max", base),
                        name="steer-every-4", steer_every=4), f)
@@ -38,6 +35,8 @@ def test_new_config_mix_and_metric_are_found_from_their_own_files(tmp_path):
         "workloads": ["another-deployment.steer-every-4"]})
     got = harness.resolve_cell(bench, "another-deployment.steer-every-4", base)
     assert got["config"]["name"] == "another-deployment"
+    ref = harness.load_reference(got["config"], base)
+    assert ref.__file__ == os.path.join(base, "another_payload.py")
     assert got["traffic"]["steer_every"] == 4
     assert "tick.count" in [m["name"] for m in got["per_layer"]]
     assert "sweep_p90_ms" not in [m["name"] for m in got["end_to_end"]]
